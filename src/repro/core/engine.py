@@ -451,13 +451,15 @@ class ParallelEngine:
                                   self.speculate, self.worker_failure_limit)}
 
     def _fallback(self) -> "LocalEngine":
-        # non-reconstructible op: host path, but any dispatch summaries it
-        # logs still land in THIS engine's report
+        # non-reconstructible op or a model op: host path, but any dispatch
+        # summaries it logs still land in THIS engine's report
         fb = LocalEngine()
         fb.dispatch_log = self.dispatch_log
         return fb
 
     def map_batches(self, op, blocks, batch_size):
+        if op.uses_model:  # the device belongs to this process, not to workers
+            return self._fallback().map_batches(op, blocks, batch_size)
         try:
             cfg = op.config()
             from repro.core.registry import create_op
@@ -507,7 +509,12 @@ class ParallelEngine:
         segment chain via the shared WindowedDispatcher — bounded adaptive
         in-flight window, speculative straggler re-dispatch, worker
         quarantine. Results are yielded in input order so outputs stay
-        deterministic (a speculative backup computes the identical block)."""
+        deterministic (a speculative backup computes the identical block).
+        A chain holding a model op runs in this process: the device belongs
+        to one process, and forked workers would each open it."""
+        if any(op.uses_model for op in ops):
+            yield from self._fallback().map_block_chain(ops, blocks, batch_size)
+            return
         try:
             cfgs = [op.config() for op in ops]
             from repro.core.registry import create_op

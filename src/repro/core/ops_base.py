@@ -293,6 +293,10 @@ class FusedOP(Operator):
     def pushdown_safe(self):  # type: ignore[override]
         return all(o.pushdown_safe for o in self.ops)
 
+    @property
+    def uses_model(self):  # type: ignore[override]
+        return any(o.uses_model for o in self.ops)
+
     def process_columns(self, block):
         # cascaded columnar filtering: each op sees only the survivors of
         # the previous ones — the same work-saving shape as process_batch

@@ -1,7 +1,6 @@
-"""Jit'd public wrapper: padding + dispatch to the Pallas kernel."""
+"""Public wrapper: uint32 folding + padding + dispatch to the Pallas kernel."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -21,20 +20,19 @@ def _bucket_up(n: int, base: int) -> int:
 
 def minhash_signatures(
     hashes: np.ndarray, mask: np.ndarray, a: np.ndarray, b: np.ndarray,
-    interpret: bool = True, bucket: bool = True,
 ) -> jnp.ndarray:
     """hashes (D, S) uint64/uint32, mask (D, S) bool, a/b (P,) any int ->
     (D, P) uint32 signatures. Inputs are folded to uint32 and padded to
     kernel block multiples.
 
-    With ``bucket`` (default), D and S pad up to power-of-two buckets
-    instead of exact block multiples: the S axis is a compile-time shape
-    (the kernel statically unrolls its chunk loop), so the streaming
-    ``SignatureBatcher`` — which dispatches super-batch after super-batch
-    with varying doc counts and shingle widths — would otherwise compile a
-    fresh kernel per distinct shape. Bucketing bounds the compile cache to
-    O(log) shapes; padded shingles carry ``mask=False`` (min-ignored) and
-    padded doc rows are sliced off, so values never change.
+    D and S pad up to power-of-two buckets instead of exact block
+    multiples: the S axis is a compile-time shape (the kernel statically
+    unrolls its chunk loop), so the streaming ``SignatureBatcher`` — which
+    dispatches super-batch after super-batch with varying doc counts and
+    shingle widths — would otherwise compile a fresh kernel per distinct
+    shape. Bucketing bounds the compile cache to O(log) shapes; padded
+    shingles carry ``mask=False`` (min-ignored), padded doc rows and
+    permutations are sliced off, so values never change.
     """
     h32 = (np.asarray(hashes, np.uint64) & 0xFFFFFFFF).astype(np.uint32) ^ (
         np.asarray(hashes, np.uint64) >> np.uint64(32)
@@ -43,8 +41,8 @@ def minhash_signatures(
     b32 = np.asarray(b, np.uint64).astype(np.uint32)
     d, s = h32.shape
     p = a32.shape[0]
-    pd = (_bucket_up(d, BLOCK_D) if bucket else d + ((-d) % BLOCK_D)) - d
-    ps = (_bucket_up(s, _MIN_BUCKET_S) - s) if bucket else 0
+    pd = _bucket_up(d, BLOCK_D) - d
+    ps = _bucket_up(s, _MIN_BUCKET_S) - s
     pp = (-p) % BLOCK_P
     if pd or ps:
         h32 = np.pad(h32, ((0, pd), (0, ps)))
@@ -53,15 +51,12 @@ def minhash_signatures(
         a32 = np.pad(a32, (0, pp), constant_values=1)
         b32 = np.pad(b32, (0, pp))
     out = minhash_pallas(
-        jnp.asarray(h32), jnp.asarray(mask), jnp.asarray(a32), jnp.asarray(b32),
-        interpret=interpret,
-    )
+        jnp.asarray(h32), jnp.asarray(mask), jnp.asarray(a32), jnp.asarray(b32))
     return out[:d, :p]
 
 
 def minhash_signatures_packed(
     values: np.ndarray, offsets: np.ndarray, a: np.ndarray, b: np.ndarray,
-    interpret: bool = True, bucket: bool = True,
 ) -> jnp.ndarray:
     """Packed-ragged entry point: ``values`` is the concatenation of every
     doc's shingle hashes in doc order, ``offsets`` (n_docs + 1,) delimits
@@ -74,8 +69,7 @@ def minhash_signatures_packed(
     n = offsets.size - 1
     if n <= 0:
         return minhash_signatures(np.zeros((0, 1), np.uint64),
-                                  np.zeros((0, 1), bool), a, b,
-                                  interpret=interpret, bucket=bucket)
+                                  np.zeros((0, 1), bool), a, b)
     if offsets[0] != 0 or offsets[-1] != values.size or np.any(np.diff(offsets) < 0):
         raise ValueError("offsets must be monotonic, start at 0 and span values")
     lens = np.diff(offsets)
@@ -84,5 +78,4 @@ def minhash_signatures_packed(
     padded = np.zeros((n, s_max), dtype=np.uint64)
     # row-major True positions of mask enumerate docs in order == values order
     padded[mask] = values
-    return minhash_signatures(padded, mask, a, b,
-                              interpret=interpret, bucket=bucket)
+    return minhash_signatures(padded, mask, a, b)

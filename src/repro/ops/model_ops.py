@@ -80,6 +80,39 @@ class NgramPerplexityFilter(_RangeFilter):
         return float(math.exp(-logp / (len(ids) - 1)))
 
 
+def batch_scorer(model):
+    """Jitted ``(params, tokens, labels, mask) -> (B,)`` per-row mean losses:
+    ONE call scores the whole batch (vmap of the model's batch-of-one
+    ``loss_fn``). Built from the model alone, so the step can be lowered and
+    compiled from abstract params (``jax.eval_shape``) without allocating
+    them."""
+    import jax
+
+    def score_one(params, tokens, labels, mask):
+        loss, _ = model.loss_fn(
+            params,
+            {"tokens": tokens[None], "labels": labels[None], "loss_mask": mask[None]},
+        )
+        return loss
+
+    return jax.jit(jax.vmap(score_one, in_axes=(None, 0, 0, 0)))
+
+
+def encode_rows(tok, texts: List[str], seq: int):
+    """Host-side next-token batch for the scorer: ``(tokens, labels, mask)``,
+    each ``(len(texts), seq)``; ``mask`` is 1.0 on the scored positions."""
+    toks = np.zeros((len(texts), seq), np.int32)
+    mask = np.zeros((len(texts), seq), np.float32)
+    for i, t in enumerate(texts):
+        ids = tok.encode(t)[: seq + 1]
+        n = max(len(ids) - 1, 1)
+        toks[i, :n] = ids[:-1][:seq] if len(ids) > 1 else [0]
+        mask[i, :n] = 1.0
+    labels = np.zeros_like(toks)
+    labels[:, :-1] = toks[:, 1:]
+    return toks, labels, mask
+
+
 @register("lm_perplexity_filter")
 class LMPerplexityFilter(_RangeFilter):
     """Perplexity from a JAX LM (model substrate), batched + jit'd.
@@ -87,6 +120,8 @@ class LMPerplexityFilter(_RangeFilter):
     ``arch`` picks any assigned architecture (reduced config by default so
     the OP runs on CPU); ``params_path`` can point at a trained checkpoint
     (e.g. produced by examples/train_e2e.py — data-model co-development).
+    A chunked SSM scores ``seq_len`` rounded up to a multiple of its chunk,
+    the only lengths its chunked scan accepts.
     """
 
     stat_key = "lm_ppl"
@@ -102,13 +137,12 @@ class LMPerplexityFilter(_RangeFilter):
         self._model = None
         self._params = None
         self._tok = None
-        self._score = None
+        self._score_batch = None
 
     def setup(self):
         if self._model is not None:
             return
         import jax
-        import jax.numpy as jnp
 
         from repro.configs import get_config
         from repro.data.tokenizer import HashWordTokenizer
@@ -123,34 +157,15 @@ class LMPerplexityFilter(_RangeFilter):
         else:
             self._params = self._model.init_params(jax.random.PRNGKey(0))
         self._tok = HashWordTokenizer(cfg.vocab_size)
-        seq = self.params["seq_len"]
-
-        def score_one(params, tokens, labels, mask):
-            loss, _ = self._model.loss_fn(
-                params,
-                {"tokens": tokens[None], "labels": labels[None], "loss_mask": mask[None]},
-            )
-            return loss
-
-        self._score = jax.jit(score_one)
-        # batched scoring: ONE jit call for the whole batch (vmap over samples)
-        self._score_batch = jax.jit(jax.vmap(score_one, in_axes=(None, 0, 0, 0)))
-        self._seq = seq
+        chunk = cfg.ssm_chunk if cfg.family == "ssm" else 1
+        self._seq = -(-self.params["seq_len"] // chunk) * chunk
+        self._score_batch = batch_scorer(self._model)
 
     def _ppl_batch(self, texts: List[str]) -> np.ndarray:
         self.setup()
         import jax.numpy as jnp
 
-        seq = self._seq
-        toks = np.zeros((len(texts), seq), np.int32)
-        mask = np.zeros((len(texts), seq), np.float32)
-        for i, t in enumerate(texts):
-            ids = self._tok.encode(t)[: seq + 1]
-            n = max(len(ids) - 1, 1)
-            toks[i, :n] = ids[:-1][:seq] if len(ids) > 1 else [0]
-            mask[i, :n] = 1.0
-        labels = np.zeros_like(toks)
-        labels[:, :-1] = toks[:, 1:]
+        toks, labels, mask = encode_rows(self._tok, texts, self._seq)
         # pad the batch dim to a multiple of 64 to bound jit retraces without
         # over-scoring (pow2 padding cost up to +33% work on odd batch sizes)
         n = len(texts)

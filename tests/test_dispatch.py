@@ -558,3 +558,54 @@ def test_run_report_surfaces_dispatch_and_monitor_rows(tmp_path):
     ex = Executor(r).explain()
     assert ex["dispatch"]["speculation"] is True
     assert ex["dispatch"]["window"]["adaptive"] is True
+
+
+# ---------------------------------------------------------------------------
+# device ownership: model ops run in the driver, never in forked workers
+# ---------------------------------------------------------------------------
+
+
+@register("pid_stamp_mapper")
+class PidStampMapper(Mapper):
+    """Stamps the pid of the process that ran it into the sample's stats."""
+
+    _name = "pid_stamp_mapper"
+
+    def process_single(self, s):
+        s.setdefault("stats", {})["pid"] = os.getpid()
+        return s
+
+
+@register("pid_stamp_model_mapper")
+class PidStampModelMapper(PidStampMapper):
+    _name = "pid_stamp_model_mapper"
+    uses_model = True
+
+
+@pytest.mark.parametrize("op_name,in_driver", [
+    ("pid_stamp_model_mapper", True),
+    ("pid_stamp_mapper", False),  # control: plain ops do reach the workers
+])
+@pytest.mark.parametrize("path", ["streaming", "barriered"])
+def test_model_op_runs_in_driver_under_parallel_engine(tmp_path, op_name,
+                                                       in_driver, path):
+    src = str(tmp_path / "in.jsonl")
+    write_jsonl(src, make_corpus(120, seed=5))
+    r = Recipe(name="pid", dataset_path=src, engine="parallel", np=2,
+               block_bytes=4096, use_fusion=False, use_reordering=False,
+               process=[{"name": "whitespace_normalization_mapper"},
+                        {"name": op_name}])
+    ex = Executor(r)
+    ds, rep = ex.run_streaming() if path == "streaming" else ex.run_barriered()
+    pids = {s["stats"]["pid"] for s in ds.samples()}
+    assert rep.errors == 0 and len(ds) == 120
+    assert (pids == {os.getpid()}) is in_driver, pids
+
+
+def test_fused_op_with_model_op_is_a_model_op():
+    from repro.core.ops_base import FusedOP
+
+    plain = create_op({"name": "text_length_filter"})
+    model = create_op({"name": "lm_perplexity_filter"})
+    assert not FusedOP([plain]).uses_model
+    assert FusedOP([plain, model]).uses_model
