@@ -122,6 +122,11 @@ class SignatureBatcher:
         self._docs, self._payloads = [], []
         if not docs:
             return [], [], np.zeros((0, self.n_perm), dtype=np.uint32)
+        with obs.timed("dedup.sign"):
+            sigs = self._sign(docs)
+        return payloads, docs, sigs
+
+    def _sign(self, docs: List[np.ndarray]) -> np.ndarray:
         self.dispatches += 1
         # kernel-batch span: flush runs driver-side, so the ambient parent
         # is the enclosing run/segment span and timing comes straight from
@@ -157,7 +162,7 @@ class SignatureBatcher:
                 sigs[i] = signature_ref(d, self._a, self._b)
         if kb is not None:
             kb.set(docs=len(docs), kernel=self.use_kernel).end()
-        return payloads, docs, sigs
+        return sigs
 
 
 # ---------------------------------------------------------------------------
@@ -508,9 +513,14 @@ class StreamingMinHashState:
                 sigs: np.ndarray) -> List[Sample]:
         """Insert a flushed super-batch into the index; returns keep-first
         survivors (empty in exact mode, which defers all emission)."""
-        kept: List[Sample] = []
         if sigs.shape[0] == 0:
-            return kept
+            return []
+        with obs.timed("dedup.index"):
+            return self._index(payloads, docs, sigs)
+
+    def _index(self, payloads: List[Sample], docs: List[np.ndarray],
+               sigs: np.ndarray) -> List[Sample]:
+        kept: List[Sample] = []
         keys = lsh_bands(sigs, self.n_bands)
         for j, sample in enumerate(payloads):
             gid = self.n_seen
@@ -525,8 +535,11 @@ class StreamingMinHashState:
             for band, head, _ in edges:
                 ok = True
                 if self.verify:
-                    ok = jaccard_unique(self.index.shingles.get(head), du) \
-                        >= self.jaccard_threshold
+                    with obs.timed("dedup.verify",
+                                   {"dedup.candidates_total": 1}) as vt:
+                        ok = jaccard_unique(self.index.shingles.get(head), du) \
+                            >= self.jaccard_threshold
+                        vt.add("dedup.duplicates_total", ok)
                     self.n_verified += 1
                 if not ok:
                     continue
@@ -614,14 +627,16 @@ class StreamingMinHashState:
                             texts = None
                     if texts is not None:
                         self._spill_lines(cb.iter_json_lines())
-                        for t in texts:
-                            self.batcher.add(t, None)
+                        with obs.timed("dedup.shingle"):
+                            for t in texts:
+                                self.batcher.add(t, None)
                     else:
                         if self.exact:
                             self._spill_samples(blk.samples)
-                        for s in blk.samples:
-                            self.batcher.add(s.get("text", ""),
-                                             None if self.exact else s)
+                        with obs.timed("dedup.shingle"):
+                            for s in blk.samples:
+                                self.batcher.add(s.get("text", ""),
+                                                 None if self.exact else s)
                     while self.batcher.ready:
                         out.extend(self._ingest(*self.batcher.flush()))
                 dt = time.perf_counter() - t0

@@ -26,7 +26,7 @@ from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import clock
+from repro.core import clock, obs
 from repro.core import schema as S
 from repro.core.columnar import ColumnBlock
 from repro.core.dispatch import (
@@ -88,12 +88,13 @@ def run_chain(
         try:
             bs = batch_size or op.default_batch_size
             out: List[Sample] = []
-            for i in range(0, len(samples), bs):
-                if should_stop is not None and should_stop():
-                    raise TaskPreempted(f"chain preempted at op[{k}] {op.name}")
-                out.extend(op.run_batch_safe(samples[i : i + bs], i))
-            if drop_empty:
-                out = [s for s in out if not S.is_empty(s)]
+            with obs.annotate(f"op:{op.name}"):
+                for i in range(0, len(samples), bs):
+                    if should_stop is not None and should_stop():
+                        raise TaskPreempted(f"chain preempted at op[{k}] {op.name}")
+                    out.extend(op.run_batch_safe(samples[i : i + bs], i))
+                if drop_empty:
+                    out = [s for s in out if not S.is_empty(s)]
         except (ChainOpFailure, TaskPreempted):
             raise
         except Exception as e:  # escaped the per-sample exception manager
@@ -139,8 +140,9 @@ def _columnar_prefix(
         t0 = time.perf_counter()
         n_in = len(block)
         try:
-            op.setup()
-            nxt = op.process_columns(block)
+            with obs.annotate(f"op:{op.name}"):
+                op.setup()
+                nxt = op.process_columns(block)
         except TaskPreempted:
             raise
         except Exception:  # noqa: BLE001 — fall back to the row path from op k

@@ -77,8 +77,8 @@ class RunReport:
     # redispatches, speculation_wins, retries, quarantined workers, window
     dispatch: List[dict] = dataclasses.field(default_factory=list)
     # merged trace for this run: {"trace_id", "root_span", "spans": [...]}
-    # (core.obs span dicts — run -> dispatch windows -> worker block spans
-    # -> synthesized per-op spans). None when tracing is disabled.
+    # (core.obs span dicts — run -> dispatch windows -> worker block
+    # spans). None when tracing is disabled.
     trace: Optional[Dict[str, Any]] = None
 
 
@@ -193,32 +193,16 @@ class Executor:
             stack.remove(sp)
 
     def _finish_run_span(self, sp, report: RunReport) -> None:
-        """End the run span, synthesize per-op spans from the monitor rows
-        (ops have no absolute timestamps — they are laid out sequentially
-        from the run start, which is exact for barriered runs and a faithful
-        plan-order approximation for pipelined segments), and attach the
-        drained trace to the report."""
+        """End the run span and attach the drained trace to the report.
+        Ops get no spans: their per-block work carries ``op:<name>``
+        profiler annotations, and their seconds are the report's per-op
+        rows."""
         if sp is None:
             return
-        t_cursor = sp.t0
-        for i, row in enumerate(report.per_op):
-            secs = float(row.get("seconds", 0.0) or 0.0)
-            op_sp = obs.start_span(sp.trace_id, f"op:{row.get('op')}",
-                                   kind="op", parent_id=sp.span_id,
-                                   t0=t_cursor, tid=1000 + i)
-            if op_sp is not None:
-                op_sp.set(n_in=row.get("in", 0), n_out=row.get("out", 0),
-                          errors=row.get("errors", 0),
-                          redispatches=row.get("redispatches", 0))
-                op_sp.end(t_cursor + secs)
-            t_cursor += secs
         sp.set(n_in=report.n_in, n_out=report.n_out, errors=report.errors,
                streaming=report.streaming, resumed_at=report.resumed_at)
         sp.end()
-        m = obs.metrics()
-        m.inc("run.rows_out_total", report.n_out)
-        if report.seconds > 0:
-            m.gauge("run.rows_per_second", report.n_out / report.seconds)
+        obs.metrics().inc("run.rows_out_total", report.n_out)
         report.trace = {"trace_id": sp.trace_id, "root_span": sp.span_id,
                         "spans": obs.drain(sp.trace_id)}
 

@@ -2,7 +2,7 @@
 metrics registry (ISSUE 8, ROADMAP item 3 on-ramp).
 
 **Spans.** A :class:`Span` is one timed unit of work — job, shard task,
-run, segment, dispatch window, op, block, kernel batch — carrying a
+run, segment, dispatch window, block, kernel batch — carrying a
 ``trace_id`` shared by every span of one logical job and a ``parent_id``
 linking it into a tree. Ids are minted at `Executor.run` / cluster
 ``submit`` and *propagated*, not re-minted, across every boundary the
@@ -24,12 +24,23 @@ and sorts — that is the driver-side merge.
 
 **Metrics.** :class:`MetricsRegistry` holds bounded counters / gauges /
 fixed-bucket histograms (queue-wait, block compute, redispatches,
-resident bytes, rows/s). ``snapshot()`` is JSON-safe; ``merge()`` folds
+resident bytes). ``snapshot()`` is JSON-safe; ``merge()`` folds
 per-process snapshots into cluster totals for ``GET /metrics``.
 
-Tracing defaults ON (cheap: in-memory append per span) but is fully
-disabled with ``DJ_OBS=0`` or :func:`disable` — the bench asserts the
-enabled-vs-disabled overhead stays ≤ 5%.
+**Timed scopes.** Work repeated per block (shingling, a signature
+dispatch, an export write) goes through :class:`timed`: it adds its
+seconds and counts to counters and buffers no span, so a long job never
+fills ``MAX_SPANS``.
+
+**The profiler's clock.** While a JAX profiler session records in this
+process, every :func:`span`, :class:`timed` scope and :func:`annotate`
+also opens a ``jax.profiler.TraceAnnotation`` of the same name, so the
+device trace shows what the host was doing in each idle gap. obs never
+imports JAX: a process that has not imported it runs no profiler.
+
+Tracing defaults ON (cheap: in-memory append per span, one counter
+update per timed scope) but is fully disabled with ``DJ_OBS=0`` or
+:func:`disable`.
 
 All timestamps come from :mod:`repro.core.clock` so tests can inject a
 fake clock and span merging stays deterministic.
@@ -38,6 +49,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import uuid
 from typing import Any, Dict, Iterable, List, Optional
@@ -83,8 +95,7 @@ class Span:
     def __init__(self, trace_id: str, name: str, kind: str = "span",
                  parent_id: Optional[str] = None,
                  span_id: Optional[str] = None,
-                 t0: Optional[float] = None,
-                 tid: Optional[Any] = None):
+                 t0: Optional[float] = None):
         self.trace_id = trace_id
         self.span_id = span_id or new_id()
         self.parent_id = parent_id
@@ -94,7 +105,7 @@ class Span:
         self.dur = 0.0
         self.attrs: Dict[str, Any] = {}
         self.pid = os.getpid()
-        self.tid = tid if tid is not None else threading.get_ident() % 100000
+        self.tid = threading.get_ident() % 100000
         self._done = False
 
     def set(self, **attrs) -> "Span":
@@ -140,6 +151,12 @@ class _TracerState:
     def current(self) -> Optional[Span]:
         st = self.stack()
         return st[-1] if st else None
+
+    def timed_stack(self) -> list:
+        st = getattr(self._local, "timed", None)
+        if st is None:
+            st = self._local.timed = []
+        return st
 
     # -- recording ------------------------------------------------------
     def record(self, span: Span) -> None:
@@ -243,6 +260,19 @@ def start_span(trace_id: Optional[str], name: str, kind: str = "span",
     return Span(trace_id, name, kind=kind, parent_id=parent_id, **kw)
 
 
+def _annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` named ``name`` while a
+    profiler session records in this process, else None. Close it with
+    ``__exit__``."""
+    jax = sys.modules.get("jax")
+    prof = getattr(jax, "profiler", None)
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    ann = prof.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 @contextlib.contextmanager
 def span(trace_id: Optional[str], name: str, kind: str = "span",
          parent_id: Optional[str] = None, **kw):
@@ -257,13 +287,76 @@ def span(trace_id: Optional[str], name: str, kind: str = "span",
         parent_id = cur.span_id if cur is not None else None
     sp = Span(trace_id, name, kind=kind, parent_id=parent_id, **kw)
     _state.stack().append(sp)
+    ann = _annotation(name)
     try:
         yield sp
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         st = _state.stack()
         if st and st[-1] is sp:
             st.pop()
         sp.end()
+
+
+@contextlib.contextmanager
+def annotate(name: str):
+    """A profiler annotation alone (no span, no counter): names a stretch
+    of host work on the device trace's clock while a profiler records."""
+    ann = _annotation(name) if _state.enabled else None
+    try:
+        yield
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+
+class timed:
+    """Timed scope for per-block work: on exit adds its seconds to the
+    counter ``<name>_seconds`` and ``counts`` (plus whatever :meth:`add`
+    gave it) to their counters; records no span. Scopes nest per thread,
+    and a scope's seconds leave out those of the scopes nested in it, so
+    the ``_seconds`` counters of one thread never count a stretch twice.
+    While a profiler records, the scope is also an annotation ``name``
+    whose metadata carries the counts. Does nothing when obs is disabled.
+    """
+
+    __slots__ = ("name", "counts", "nested", "_t0", "_ann")
+
+    def __init__(self, name: str, counts: Optional[Dict[str, float]] = None):
+        self.name = name
+        self.counts = {k: float(v) for k, v in counts.items()} if counts else {}
+        self.nested = 0.0
+        self._t0: Optional[float] = None
+        self._ann = None
+
+    def add(self, name: str, v: float = 1.0) -> None:
+        self.counts[name] = self.counts.get(name, 0.0) + float(v)
+
+    def __enter__(self) -> "timed":
+        if _state.enabled:
+            self._ann = _annotation(self.name)
+            _state.timed_stack().append(self)
+            self._t0 = clock.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._t0 is None:
+            return False
+        dt = clock.monotonic() - self._t0
+        st = _state.timed_stack()
+        if st and st[-1] is self:
+            st.pop()
+            if st:
+                st[-1].nested += dt
+        _metrics.inc(self.name + "_seconds", dt - self.nested)
+        for k, v in self.counts.items():
+            _metrics.inc(k, v)
+        if self._ann is not None:
+            if self.counts:
+                self._ann.set_metadata(**self.counts)
+            self._ann.__exit__(None, None, None)
+        return False
 
 
 def record_span_dict(d: Optional[Dict[str, Any]]) -> None:

@@ -293,6 +293,12 @@ class BlockWriter:
             self._w = self._fh
 
     def write_block(self, block: SampleBlock) -> int:
+        from repro.core import obs  # obs imports this module
+
+        with obs.timed("export.write"):
+            return self._write(block)
+
+    def _write(self, block: SampleBlock) -> int:
         lines = getattr(block, "iter_json_lines", None)
         if lines is not None:
             # ColumnBlock export codec: canonical lines assembled straight
@@ -394,9 +400,13 @@ class BlockPrefetcher:
                 return
 
     def __iter__(self) -> Iterator[SampleBlock]:
+        from repro.core import obs  # obs imports this module
+
         try:
             while True:
-                item = self._q.get()
+                # the consumer's wait for the next decoded input block
+                with obs.timed("stream.wait"):
+                    item = self._q.get()
                 if item is self._DONE:
                     if self._err is not None:
                         raise self._err

@@ -14,6 +14,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.ops_base import Filter, Mapper, shared_words
 from repro.core.registry import register
 from repro.ops.text_filters import _RangeFilter
@@ -100,16 +101,18 @@ def batch_scorer(model):
 
 def encode_rows(tok, texts: List[str], seq: int):
     """Host-side next-token batch for the scorer: ``(tokens, labels, mask)``,
-    each ``(len(texts), seq)``; ``mask`` is 1.0 on the scored positions."""
+    each ``(len(texts), seq)``; ``mask`` is 1.0 on the scored positions.
+    The target of each scored position is the id that follows it: the next
+    word, EOS after the last, or the first word past the cut."""
     toks = np.zeros((len(texts), seq), np.int32)
+    labels = np.zeros((len(texts), seq), np.int32)
     mask = np.zeros((len(texts), seq), np.float32)
     for i, t in enumerate(texts):
         ids = tok.encode(t)[: seq + 1]
-        n = max(len(ids) - 1, 1)
-        toks[i, :n] = ids[:-1][:seq] if len(ids) > 1 else [0]
+        n = len(ids) - 1
+        toks[i, :n] = ids[:n]
+        labels[i, :n] = ids[1:]
         mask[i, :n] = 1.0
-    labels = np.zeros_like(toks)
-    labels[:, :-1] = toks[:, 1:]
     return toks, labels, mask
 
 
@@ -165,20 +168,25 @@ class LMPerplexityFilter(_RangeFilter):
         self.setup()
         import jax.numpy as jnp
 
-        toks, labels, mask = encode_rows(self._tok, texts, self._seq)
-        # pad the batch dim to a multiple of 64 to bound jit retraces without
-        # over-scoring (pow2 padding cost up to +33% work on odd batch sizes)
-        n = len(texts)
-        nb = max(64, ((n + 63) // 64) * 64)
-        if nb != n:
-            toks = np.pad(toks, ((0, nb - n), (0, 0)))
-            labels = np.pad(labels, ((0, nb - n), (0, 0)))
-            mask = np.pad(mask, ((0, nb - n), (0, 0)))
-            mask[n:, 0] = 1.0  # avoid 0/0 in padded rows
-        losses = self._score_batch(
-            self._params, jnp.asarray(toks), jnp.asarray(labels), jnp.asarray(mask)
-        )
-        losses = np.asarray(losses, np.float64)[:n]
+        with obs.timed("scorer.encode"):
+            toks, labels, mask = encode_rows(self._tok, texts, self._seq)
+            scored = float(mask.sum())
+            # pad the batch dim to a multiple of 64 to bound jit retraces
+            # without over-scoring (pow2 padding cost up to +33% work on odd
+            # batch sizes)
+            n = len(texts)
+            nb = max(64, ((n + 63) // 64) * 64)
+            if nb != n:
+                toks = np.pad(toks, ((0, nb - n), (0, 0)))
+                labels = np.pad(labels, ((0, nb - n), (0, 0)))
+                mask = np.pad(mask, ((0, nb - n), (0, 0)))
+                mask[n:, 0] = 1.0  # avoid 0/0 in padded rows
+        with obs.timed("scorer.step", {"scorer.tokens_scored": scored,
+                                       "scorer.tokens_padded": toks.size - scored}):
+            losses = self._score_batch(
+                self._params, jnp.asarray(toks), jnp.asarray(labels), jnp.asarray(mask)
+            )
+            losses = np.asarray(losses, np.float64)[:n]
         return np.exp(np.minimum(losses, 30.0))
 
     def process_batch(self, batch):

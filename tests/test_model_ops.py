@@ -53,3 +53,23 @@ def test_lm_perplexity_rounded_length_scores_as_requested_multiple():
                       "seq_len": n}) for n in (9, 16)]
     a, b = (_scored(op, samples) for op in ops)
     np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("words,seq", [(5, 16), (40, 16)])  # a short row, a cut row
+def test_encode_rows_targets_are_the_next_ids(words, seq):
+    """Every scored position's target is the id after it: the next word, EOS
+    after the last word of a short row, the first word past the cut of a
+    long one; never the pad id."""
+    from repro.data.tokenizer import HashWordTokenizer
+    from repro.ops.model_ops import encode_rows
+
+    tok = HashWordTokenizer(512)
+    text = " ".join(f"w{i}" for i in range(words))
+    ids = tok.encode(text)
+    toks, labels, mask = encode_rows(tok, [text], seq)
+    n = int(mask[0].sum())
+    assert n == min(len(ids) - 1, seq)
+    np.testing.assert_array_equal(toks[0, :n], ids[:n])
+    np.testing.assert_array_equal(labels[0, :n], ids[1:n + 1])
+    assert labels[0, n - 1] == (tok.EOS if words + 1 < seq else ids[seq])
+    assert labels[0, n - 1] != tok.PAD

@@ -156,6 +156,65 @@ def test_chrome_trace_is_valid_catapult(fake):
 
 
 # ---------------------------------------------------------------------------
+# timed scopes and profiler annotations
+# ---------------------------------------------------------------------------
+
+
+def _nested_scopes(fake):
+    """Two rounds of an outer scope (1.0 s of its own) around an inner one
+    (0.5 s, with counts): the inner's time leaves the outer's counter."""
+    for _ in range(2):
+        with obs.timed("t.outer"):
+            fake.tick(0.25)
+            with obs.timed("t.inner", {"t.rows_total": 3}) as inner:
+                fake.tick(0.5)
+                inner.add("t.kept_total", True)
+            fake.tick(0.75)
+
+
+def test_timed_scope_counters_add_up(fake):
+    _nested_scopes(fake)
+    c = obs.metrics().snapshot()["counters"]
+    assert c["t.outer_seconds"] == pytest.approx(2.0)
+    assert c["t.inner_seconds"] == pytest.approx(1.0)
+    assert c["t.rows_total"] == 6.0 and c["t.kept_total"] == 2.0
+    json.dumps(c)  # plain floats, whatever the counts were given as
+
+
+def test_timed_scope_buffers_no_span(fake):
+    with obs.span("t", "run", kind="run"):
+        for _ in range(obs.MAX_SPANS + 10):
+            with obs.timed("t.block"):
+                fake.tick(0.001)
+    assert [s["name"] for s in obs.drain()] == ["run"]
+    assert obs.tracer().dropped == 0
+    c = obs.metrics().snapshot()["counters"]
+    assert c["t.block_seconds"] == pytest.approx((obs.MAX_SPANS + 10) * 0.001)
+
+
+def test_timed_scope_disabled_counts_nothing(fake):
+    obs.disable()
+    _nested_scopes(fake)
+    assert obs.metrics().snapshot()["counters"] == {}
+
+
+def test_obs_does_not_import_jax():
+    code = ("import sys\n"
+            "from repro.core import obs, storage\n"
+            "with obs.span('t', 'run'):\n"
+            "    with obs.timed('x.step', {'x.rows_total': 2}):\n"
+            "        with obs.annotate('op:y'):\n"
+            "            pass\n"
+            "assert obs.metrics().snapshot()['counters']['x.rows_total'] == 2\n"
+            "print('jax' in sys.modules)\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=60)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == "False"
+
+
+# ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
 
@@ -387,7 +446,11 @@ def test_run_report_carries_trace_with_op_spans(tmp_path):
     assert tr and tr["trace_id"] and tr["root_span"]
     spans = tr["spans"]
     kinds = sorted(s["kind"] for s in spans)
-    assert kinds.count("run") == 1 and kinds.count("op") == 2
+    # ops get no synthesized spans: their times are the per-op rows, and
+    # their per-block work carries op:<name> profiler annotations
+    assert kinds.count("run") == 1 and "op" not in kinds
+    assert [row["op"] for row in rep.per_op] == [
+        "whitespace_normalization_mapper", "text_length_filter"]
     tree = obs.span_tree(spans)
     assert tree["roots"] == [tr["root_span"]] and tree["orphans"] == []
 
@@ -396,7 +459,7 @@ def test_parallel_run_ships_block_spans_over_ipc(tmp_path):
     _, rep = _run(tmp_path, engine="parallel", np=2, block_bytes=2000)
     spans = rep.trace["spans"]
     kinds = {s["kind"] for s in spans}
-    assert {"run", "op", "dispatch", "block"} <= kinds
+    assert {"run", "dispatch", "block"} <= kinds and "op" not in kinds
     blocks = [s for s in spans if s["kind"] == "block"]
     dispatch = [s for s in spans if s["kind"] == "dispatch"]
     assert all(b["parent_id"] == dispatch[0]["span_id"] for b in blocks), \

@@ -93,7 +93,7 @@ def test_trace_continuity_across_sigkill_failover(tmp_path):
         f"orphan spans after failover: {tree['orphans']}"
 
     kinds = {s["kind"] for s in spans}
-    assert {"job", "shards", "lease", "run", "op"} <= kinds
+    assert {"job", "shards", "lease", "run"} <= kinds and "op" not in kinds
 
     # the killed shard was re-leased: its accepted attempt is 2, and the
     # lease span from attempt 2 made it into the merged trace
