@@ -64,6 +64,7 @@ from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple
 import numpy as np
 
 from repro.core import obs
+from repro.core.dedup.block_shingle import block_shingle_hashes
 from repro.core.dedup.minhash import (
     jaccard_unique, lsh_bands, make_permutations, shingle_hashes,
     signatures_batch_vectorized,
@@ -107,6 +108,21 @@ class SignatureBatcher:
         self._docs.append(shingle_hashes(text, n=self.ngram))
         self._payloads.append(payload)
         self.docs_in += 1
+
+    def add_block(self, texts: List[str], payloads: List[Any],
+                  utf8: Optional[Tuple[np.ndarray, bytes]] = None) -> None:
+        """``add`` for every document of an input block. On the kernel path
+        the whole block is shingled at once and its n-grams hashed on the
+        device (``block_shingle_hashes``; ``utf8`` is the block's text
+        column as ``(offsets, buffer)`` where it has one), with the same
+        values as ``add``."""
+        if not self.use_kernel:
+            for t, p in zip(texts, payloads):
+                self.add(t, p)
+            return
+        self._docs.extend(block_shingle_hashes(texts, n=self.ngram, utf8=utf8))
+        self._payloads.extend(payloads)
+        self.docs_in += len(texts)
 
     @property
     def pending(self) -> int:
@@ -617,7 +633,7 @@ class StreamingMinHashState:
                             self._spill_samples(blk.samples)
                         out.extend(self._ingest(payloads, docs, sigs))
                 else:
-                    texts = None
+                    texts = utf8 = None
                     if cb is not None and self.exact and "py" not in cb.kinds:
                         # validate the text column BEFORE spilling so a
                         # fallback can never double-spill the block
@@ -625,18 +641,25 @@ class StreamingMinHashState:
                             texts = cb.string_values("text")
                         except (TypeError, ValueError):
                             texts = None
+                    if cb is not None and self.use_kernel:
+                        try:  # the block shingler reads the column's bytes
+                            utf8 = cb.str_column("text")
+                        except TypeError:
+                            pass
                     if texts is not None:
                         self._spill_lines(cb.iter_json_lines())
                         with obs.timed("dedup.shingle"):
-                            for t in texts:
-                                self.batcher.add(t, None)
+                            self.batcher.add_block(texts, [None] * len(texts),
+                                                   utf8=utf8)
                     else:
                         if self.exact:
                             self._spill_samples(blk.samples)
                         with obs.timed("dedup.shingle"):
-                            for s in blk.samples:
-                                self.batcher.add(s.get("text", ""),
-                                                 None if self.exact else s)
+                            samples = blk.samples
+                            self.batcher.add_block(
+                                [s.get("text", "") for s in samples],
+                                [None] * len(samples) if self.exact else samples,
+                                utf8=utf8)
                     while self.batcher.ready:
                         out.extend(self._ingest(*self.batcher.flush()))
                 dt = time.perf_counter() - t0

@@ -79,3 +79,15 @@ def test_scorer_step_fits_one_v5e(one_chip, no_persistent_cache):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 5e9  # the full-width f32 params
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_LIMIT
+
+
+def test_blake2b64_tile_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The shingle hash at the one tile shape the block shingler dispatches."""
+    from repro.kernels.blake2b.blake2b import blake2b64_tile
+    from repro.kernels.blake2b.ops import TILE
+
+    compiled = blake2b64_tile.lower(
+        _abstract((TILE // 4, 128), jnp.uint32, one_chip),
+        _abstract((TILE // 128, 128), jnp.int32, one_chip),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
