@@ -8,10 +8,11 @@ window is enough) gives the program's reading of each compared number. Then
 the control is put in the program's place and judged by the same check, under
 the cell's own limits (``bench/check.py``), which has to find it not correct:
 
-- a cell with a scorer: the exported ``lm_ppl`` of the checked rows replaced
-  by the reference's own, with every matmul operand in float8
-  (``float8_e4m3fn``, one scale per tensor), one step below the bfloat16 the
-  configuration states (``scorer_gap_nats``);
+- a cell with a scorer (``scorer_gap_nats``): the exported ``lm_ppl`` of
+  the checked rows replaced by those of the reference the scorer block
+  names, every matmul operand rounded to its ``control_dtype``
+  (``float8_e4m3fn``, one scale per tensor: one step below the bfloat16 the
+  configuration states);
 - a cell without one: the stream run with half the configured LSH bands,
   twice the rows each, the cheaper index a later change could be tempted by,
   checked against the configured banding (``dedup_rows_wrong``).
@@ -81,17 +82,18 @@ def half_bands(cfg: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def scorer_control(detail: Dict[str, Any], seed: int) -> Dict[str, Any]:
-    """The check's result with the float8 reference's ``lm_ppl`` in place of
-    the program's on the rows the check compares."""
+    """The check's result with the ``lm_ppl`` of the scorer's reference in
+    its ``control_dtype`` in place of the program's, on the rows the check
+    compares."""
     from bench import check
-    from bench.reference import mamba2
+    from bench.reference import scorer_reference
 
     cfg = detail["cfg"]
     sc = cfg["scorer"]
     got = [json.loads(x) for x in check.read_lines(detail["export"])]
     pick = check.pick_rows(cfg, got, seed)
-    low = mamba2.row_losses(sc, [got[i]["text"] for i in pick], sc["seq_len"],
-                            quantize=sc["control_dtype"])
+    low = scorer_reference(sc).row_losses(sc, [got[i]["text"] for i in pick], sc["seq_len"],
+                                          quantize=sc["control_dtype"])
     for i, loss in zip(pick, low):
         got[i]["stats"]["lm_ppl"] = math.exp(float(loss))
     path = detail["export"] + ".control"

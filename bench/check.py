@@ -15,9 +15,10 @@ compared, each with its limit in the configuration file:
 - ``export_lines_wrong``: exported lines that are not the compact UTF-8 JSON
   encoding of their own content, or whose fields are not the expected ones;
 - ``scorer_gap_nats`` (with a scorer): the widest gap between a row's
-  exported ``ln(lm_ppl)`` and the reference's mean loss over the same
-  tokens, on ``rows_checked`` exported rows: the one with the most scored
-  tokens and the rest drawn with the run's seed.
+  exported ``ln(lm_ppl)`` and the mean loss over the same tokens of the
+  reference the scorer block names (``reference.scorer_reference``), on
+  ``rows_checked`` exported rows: the one with the most scored tokens and
+  the rest drawn with the run's seed.
 """
 from __future__ import annotations
 
@@ -30,7 +31,7 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
-from bench.reference import mamba2, minhash, text
+from bench.reference import minhash, scorer_reference, text
 
 
 def read_lines(path: str) -> List[bytes]:
@@ -115,7 +116,8 @@ def pick_rows(cfg: Dict[str, Any], got: List[Dict[str, Any]], seed: int) -> List
     run's seed."""
     sc = cfg["scorer"]
     n = min(sc["rows_checked"], len(got))
-    toks = [mamba2.real_tokens(r["text"], sc["seq_len"]) for r in got]
+    real_tokens = scorer_reference(sc).real_tokens
+    toks = [real_tokens(r["text"], sc["seq_len"]) for r in got]
     longest = int(np.argmax(toks))
     rest = [i for i in range(len(got)) if i != longest]
     return [longest] + sorted(np.random.default_rng(seed).choice(rest, n - 1, replace=False).tolist()
@@ -127,16 +129,17 @@ def scorer_gap(cfg: Dict[str, Any], got: List[Dict[str, Any]], seed: int
     """Widest |ln(lm_ppl) - reference loss| over the checked rows, and the
     rows with both readings."""
     sc = cfg["scorer"]
+    model = scorer_reference(sc)
     pick = pick_rows(cfg, got, seed)
     texts = [got[i]["text"] for i in pick]
-    ref = mamba2.row_losses(sc, texts, sc["seq_len"])
+    ref = model.row_losses(sc, texts, sc["seq_len"])
     gaps = []
     for i, r in zip(pick, ref):
         ppl = got[i].get("stats", {}).get("lm_ppl")
         ok = isinstance(ppl, float) and math.isfinite(ppl) and ppl > 0
         gaps.append(abs(math.log(ppl) - r) if ok else math.inf)
     return max(gaps), {"texts": texts, "ref": ref.tolist(), "gaps": gaps,
-                       "tokens": [mamba2.real_tokens(t, sc["seq_len"]) for t in texts]}
+                       "tokens": [model.real_tokens(t, sc["seq_len"]) for t in texts]}
 
 
 def run(cfg: Dict[str, Any], corpus: str, export: str, n_in: int, seed: int,
@@ -181,8 +184,9 @@ def run(cfg: Dict[str, Any], corpus: str, export: str, n_in: int, seed: int,
         gap, out["scorer_rows"] = scorer_gap(cfg, got, seed) if got else (math.inf, {})
         numbers["scorer_gap_nats"] = gap
         w0, w1 = window_rows
-        out["window_tokens"] = sum(mamba2.real_tokens(r["text"], cfg["scorer"]["seq_len"])
-                                   for r in got[w0:w1])
+        sc = cfg["scorer"]
+        real_tokens = scorer_reference(sc).real_tokens
+        out["window_tokens"] = sum(real_tokens(r["text"], sc["seq_len"]) for r in got[w0:w1])
     for k, v in numbers.items():
         out["numbers"][k] = {"value": v, "limit": limits[k], "ok": v <= limits[k]}
     return out

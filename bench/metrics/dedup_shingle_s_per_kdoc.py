@@ -1,5 +1,5 @@
 """Seconds the dedup stage spent shingling its input blocks (``dedup.shingle``:
-the ``SignatureBatcher.add`` loop of each block), per 1,000 input documents."""
+``SignatureBatcher.add_block`` on each block), per 1,000 input documents."""
 from bench.metrics._program import seconds_per_kdoc
 
 

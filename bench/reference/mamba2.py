@@ -33,7 +33,12 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from bench.costs import mamba2_flops_per_token
+
 PAD, BOS, EOS = 0, 1, 2
+# the scorer keys of the program's reduced mamba2-1.3b, short rows, few checked
+TINY = {"n_layers": 2, "d_model": 64, "vocab_size": 512, "ssm_state": 16,
+        "ssm_headdim": 16, "ssm_chunk": 8, "seq_len": 16, "rows_checked": 16}
 
 
 def tokenize(text: str, vocab_size: int) -> List[int]:
@@ -65,6 +70,11 @@ def encode(texts: Sequence[str], vocab_size: int, seq: int
 def real_tokens(text: str, seq: int) -> int:
     """Positions the scorer scores for one text: words + 1, at most ``seq``."""
     return min(len(text.split()) + 1, seq)
+
+
+def flops_per_token(cfg: Dict[str, Any]) -> int:
+    """Forward FLOPs per token of the chunked SSD at the configured chunk."""
+    return mamba2_flops_per_token(cfg, cfg["ssm_chunk"])
 
 
 def dims(cfg: Dict[str, Any]) -> Dict[str, int]:

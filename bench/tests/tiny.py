@@ -1,5 +1,5 @@
 """Tiny cells for CPU tests: the real configurations and mixes with the
-scorer at the program's reduced size, short sequences, small blocks and
+scorer at its reference's ``TINY`` size, short sequences, small blocks and
 super-batches, and a corpus of a few hundred documents."""
 from __future__ import annotations
 
@@ -8,17 +8,14 @@ from pathlib import Path
 from typing import Any, Dict
 
 from bench import run
+from bench.reference import scorer_reference
 
 # The scorer's limit at this size, set from CPU readings on 64 web rows of
 # the program's model with next-token targets (bfloat16, 0.0007 nats) and of
 # the float8 control (0.010 nats).
 TINY_LIMIT_NATS = 0.005
-# per-layer metrics of a scorer cell, which BENCHMARK.json lists once such a
-# cell is in it
-SCORER_METRICS = [{"name": "scorer_s_per_kdoc", "unit": "s/kdoc"},
-                  {"name": "scorer_mfu", "unit": "%"}]
-REDUCED_SCORER = {"n_layers": 2, "d_model": 64, "vocab_size": 512, "ssm_state": 16,
-                  "ssm_headdim": 16, "ssm_chunk": 8, "seq_len": 16, "rows_checked": 16}
+# a per-layer reader of a scorer cell that BENCHMARK.json does not list
+SCORER_METRICS = [{"name": "scorer_s_per_kdoc", "unit": "s/kdoc"}]
 
 
 def tiny_config(name: str) -> Dict[str, Any]:
@@ -30,7 +27,7 @@ def tiny_config(name: str) -> Dict[str, Any]:
         if step["name"] == "lm_perplexity_filter":
             step.update(reduced=True, seq_len=16)
     if "scorer" in cfg:
-        cfg["scorer"].update(REDUCED_SCORER)
+        cfg["scorer"].update(scorer_reference(cfg["scorer"]).TINY)
         cfg["limits"]["scorer_gap_nats"] = TINY_LIMIT_NATS
     return cfg
 
