@@ -23,6 +23,8 @@ _ARCH_MODULES: Dict[str, str] = {
     "recurrentgemma-9b": "repro.configs.recurrentgemma_9b",
     "mamba2-1.3b": "repro.configs.mamba2_13b",
     "whisper-tiny": "repro.configs.whisper_tiny",
+    "granite-4.0-h-small": "repro.configs.granite4h_small",
+    "granite-4.0-h-small-ep8": "repro.configs.granite4h_small_ep8",
 }
 
 

@@ -57,6 +57,18 @@ class ModelConfig:
     lru_width: int = 0
     local_window: int = 2048
 
+    # --- hybrid MoE (granite-4.0-h): one mixer kind per layer, each layer
+    # followed by a dropless expert layer plus a shared expert ---
+    layer_pattern: Tuple[str, ...] = ()  # "mamba" | "attention", one per layer
+    experts_held: Tuple[int, ...] = ()  # expert ids this chip holds; () = all
+    shared_d_ff: int = 0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0
+    use_rope: bool = True  # False: no positional encoding (NoPE)
+    param_dtype: str = "float32"
+
     # --- encoder-decoder (whisper) ---
     n_enc_layers: int = 0
     src_len: int = 0  # encoder source positions (precomputed frame embeds)
@@ -97,6 +109,15 @@ class ModelConfig:
     @property
     def ssm_nheads(self) -> int:
         return self.d_inner // self.ssm_headdim
+
+    @property
+    def has_ssm_layers(self) -> bool:
+        """Whether some layer runs the chunked SSD scan."""
+        return self.family == "ssm" or "mamba" in self.layer_pattern
+
+    @property
+    def held_experts(self) -> Tuple[int, ...]:
+        return self.experts_held or tuple(range(self.n_experts))
 
     @property
     def is_subquadratic(self) -> bool:
@@ -162,6 +183,12 @@ class ModelConfig:
             for kind in (self.hybrid_pattern or ("rec", "rec", "attn"))[:rem]:
                 blk = self._attn_params() if kind == "attn" else self._rglru_layer_params()
                 body += blk + self._mlp_params(self.d_ff) + 2 * self.d_model
+        elif self.family == "hybrid_moe":
+            ffn = (len(self.held_experts) * self._mlp_params(self.d_ff)
+                   + self.d_model * self.n_experts + self._mlp_params(self.shared_d_ff))
+            body = sum((self._ssm_layer_params() if k == "mamba"
+                        else self._attn_params()) + ffn + 2 * self.d_model
+                       for k in self.layer_pattern)
         elif self.family == "moe":
             moe_total, _ = self._moe_layer_params()
             body = self.n_layers * (self._attn_params() + moe_total + 2 * self.d_model)

@@ -1,4 +1,11 @@
-"""Mamba2-1.3B — SSD (state-space duality), attention-free [arXiv:2405.21060]."""
+"""Mamba2-1.3B — SSD (state-space duality), attention-free [arXiv:2405.21060].
+
+Widths from the state-spaces/mamba2-1.3b config (d_model 2048, n_layer 48,
+tied embeddings, vocabulary padded to a multiple of 16) and the Mamba-2
+layer's defaults in the paper's code (d_state 128, headdim 64, expand 2,
+ngroups 1, d_conv 4, chunk 256). ``vocab_size`` 50280 is assumed: the
+published config gives 50277, which pads to the same 50288 rows.
+"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -17,7 +24,7 @@ CONFIG = ModelConfig(
     ssm_conv=4,
     ssm_ngroups=1,
     tie_embeddings=True,
-    source="arXiv:2405.21060; unverified",
+    source="arXiv:2405.21060; hf:state-spaces/mamba2-1.3b config",
 )
 
 

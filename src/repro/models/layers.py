@@ -105,8 +105,9 @@ def _chunk_mask(sq: int, skv: int, chunk: int, c_idx, causal: bool,
     return mask
 
 
-def _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset):
+def _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset, scale=None):
     """Online-softmax forward. q5: (b, sq, hkv, g, hd); k/v: (b, skv, hkv, hd).
+    Scores are scaled by ``scale`` (default 1/sqrt(hd)).
 
     Returns out5 (b, sq, hkv, g, hd) and lse (b, hkv, g, sq) fp32.
     """
@@ -118,7 +119,7 @@ def _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_chunks = (skv + pad) // chunk
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qs = (q5.astype(COMPUTE_DTYPE) * scale).transpose(0, 2, 3, 1, 4)  # (b,k,g,sq,hd)
     qs = logical_constraint(qs, _QS_AXES)
     k_sc = k.reshape(b, n_chunks, chunk, hkv, hd).transpose(1, 0, 2, 3, 4)
@@ -157,21 +158,21 @@ def _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset):
 from functools import partial as _partial  # noqa: E402
 
 
-@_partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q5, k, v, causal=True, window=None, chunk=1024, q_offset=0):
+@_partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention(q5, k, v, causal=True, window=None, chunk=1024, q_offset=0, scale=None):
     """Flash attention (pure-jax custom_vjp): saves only (out, lse); the
     backward re-streams KV chunks — no O(sq*skv) tensor is ever saved.
     GQA-native: q5 (b, sq, hkv, g, hd) against k/v (b, skv, hkv, hd)."""
-    out, _ = _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset)
+    out, _ = _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset, scale)
     return out
 
 
-def _flash_fwd(q5, k, v, causal, window, chunk, q_offset):
-    out, lse = _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset)
+def _flash_fwd(q5, k, v, causal, window, chunk, q_offset, scale):
+    out, lse = _flash_fwd_scan(q5, k, v, causal, window, chunk, q_offset, scale)
     return out, (q5, k, v, out, lse)
 
 
-def _flash_bwd(causal, window, chunk, q_offset, res, d_out):
+def _flash_bwd(causal, window, chunk, q_offset, scale, res, d_out):
     q5, k, v, out, lse = res
     b, sq, hkv, g, hd = q5.shape
     skv = k.shape[1]
@@ -180,7 +181,7 @@ def _flash_bwd(causal, window, chunk, q_offset, res, d_out):
     kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else k
     vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else v
     n_chunks = (skv + pad) // chunk
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
 
     qs = logical_constraint(q5.astype(COMPUTE_DTYPE).transpose(0, 2, 3, 1, 4), _QS_AXES)
     do = logical_constraint(d_out.astype(COMPUTE_DTYPE).transpose(0, 2, 3, 1, 4), _QS_AXES)
@@ -230,13 +231,15 @@ def attention_chunked(
     causal: bool = True,
     window: Optional[int] = None,
     chunk: int = 1024,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Flash-attention wrapper at (b, s, heads, hd) layout (GQA handled
-    natively inside — KV is never repeated at full sequence length)."""
+    natively inside — KV is never repeated at full sequence length).
+    Scores are scaled by ``scale`` (default 1/sqrt(hd))."""
     b, sq, hq, hd = q.shape
     hkv = k.shape[2]
     q5 = q.reshape(b, sq, hkv, hq // hkv, hd)
-    out5 = flash_attention(q5, k, v, causal, window, chunk, q_offset)
+    out5 = flash_attention(q5, k, v, causal, window, chunk, q_offset, scale)
     return out5.reshape(b, sq, hq, hd)
 
 
@@ -269,6 +272,7 @@ def attention_decode(
     v_cache: jax.Array,
     cache_len: jax.Array,  # (b,) or scalar — number of valid cache entries
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Single-token attention over a pre-allocated cache.
 
@@ -281,7 +285,7 @@ def attention_decode(
     b, _, hq, hd = q.shape
     hkv, skv = k_cache.shape[1], k_cache.shape[2]
     g = hq // hkv
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     # f32 at slice level: decode is HBM-bound, and explicit casts avoid the
     # CPU backend's whole-cache bf16->f32 operand mirror (see decode_attn.py)
     qc = (q.astype(jnp.float32) * scale)[:, 0].reshape(b, hkv, g, hd)
@@ -351,13 +355,9 @@ def lm_logits(x: jax.Array, head: jax.Array) -> jax.Array:
     return jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
 
 
-def softmax_xent(
-    logits: jax.Array,
-    labels: jax.Array,
-    mask: Optional[jax.Array] = None,
-    valid_vocab: Optional[int] = None,
-):
-    """Mean next-token cross entropy. logits (b, s, v) / labels (b, s).
+def token_xent(logits: jax.Array, labels: jax.Array,
+               valid_vocab: Optional[int] = None) -> jax.Array:
+    """Next-token cross entropy at each position (b, s), in float32.
 
     ``valid_vocab`` masks padded vocab columns (vocab padded for sharding).
     """
@@ -367,7 +367,25 @@ def softmax_xent(
         logits32 = jnp.where(col < valid_vocab, logits32, -jnp.inf)
     lse = jax.nn.logsumexp(logits32, axis=-1)
     label_logit = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
-    nll = lse - label_logit
+    return lse - label_logit
+
+
+def row_xent(logits: jax.Array, labels: jax.Array, mask: jax.Array,
+             valid_vocab: Optional[int] = None) -> jax.Array:
+    """Each row's mean cross entropy over its masked positions (b,)."""
+    mask = mask.astype(jnp.float32)
+    nll = token_xent(logits, labels, valid_vocab)
+    return jnp.sum(nll * mask, axis=-1) / jnp.maximum(jnp.sum(mask, axis=-1), 1.0)
+
+
+def softmax_xent(
+    logits: jax.Array,
+    labels: jax.Array,
+    mask: Optional[jax.Array] = None,
+    valid_vocab: Optional[int] = None,
+):
+    """Mean next-token cross entropy. logits (b, s, v) / labels (b, s)."""
+    nll = token_xent(logits, labels, valid_vocab)
     if mask is not None:
         mask = mask.astype(jnp.float32)
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)

@@ -133,43 +133,84 @@ def ssd_decode_step(
     return state, y
 
 
+def mixer_dims(cfg: ModelConfig):
+    """``(d_inner, heads, groups, state, conv channels)`` of a Mamba-2 mixer."""
+    di = cfg.d_inner
+    g, ds = cfg.ssm_ngroups, cfg.ssm_state
+    return di, cfg.ssm_nheads, g, ds, di + 2 * g * ds
+
+
+def mixer_specs(cfg: ModelConfig, nl: int) -> Dict[str, mod.ParamSpec]:
+    """Parameters of ``nl`` stacked Mamba-2 mixers, their pre-norm included."""
+    d = cfg.d_model
+    di, nh, g, ds, conv_dim = mixer_dims(cfg)
+    proj_out = 2 * di + 2 * g * ds + nh
+    return {
+        "norm": mod.spec((nl, d), ("layers", "embed"), init="ones"),
+        "w_in": mod.spec((nl, d, proj_out), ("layers", "embed", "ssm_inner"), init="scaled"),
+        "conv_w": mod.spec((nl, conv_dim, cfg.ssm_conv), ("layers", "ssm_inner", "conv"), init="scaled"),
+        "conv_b": mod.spec((nl, conv_dim), ("layers", "ssm_inner"), init="zeros"),
+        "dt_bias": mod.spec((nl, nh), ("layers", "ssm_heads"), init="zeros"),
+        "a_log": mod.spec((nl, nh), ("layers", "ssm_heads"), init="zeros"),
+        "d_skip": mod.spec((nl, nh), ("layers", "ssm_heads"), init="ones"),
+        "norm_g": mod.spec((nl, di), ("layers", "ssm_inner"), init="ones"),
+        "w_out": mod.spec((nl, di, d), ("layers", "ssm_inner", "embed"), init="scaled"),
+    }
+
+
+def mixer(cfg: ModelConfig, p, h, state=None):
+    """The Mamba-2 mixer on normed input ``h`` (b, s, d): in-projection,
+    causal conv, SSD, ``rmsnorm(y * silu(z))``, out-projection. Returns
+    ``(out, (conv_state, ssm_state))``: with ``state`` None the whole
+    sequence runs through the chunked scan and the states are those after its
+    last position; with ``state`` one position steps the recurrence."""
+    di, nh, g, ds, conv_dim = mixer_dims(cfg)
+    zxbcdt = jnp.einsum("bsd,dp->bsp", h, p["w_in"].astype(h.dtype))
+    z = zxbcdt[..., :di]
+    xbc_raw = zxbcdt[..., di : di + conv_dim]
+    dt = zxbcdt[..., di + conv_dim :]
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+
+    if state is None:
+        xbc = causal_conv1d(xbc_raw, p["conv_w"], p["conv_b"])
+        xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(h.dtype)
+        x_in = xbc[..., :di].reshape(*xbc.shape[:2], nh, cfg.ssm_headdim)
+        b_mat = xbc[..., di : di + g * ds].reshape(*xbc.shape[:2], g, ds)
+        c_mat = xbc[..., di + g * ds :].reshape(*xbc.shape[:2], g, ds)
+        y, final = ssd_chunked(x_in, dt, p["a_log"], b_mat, c_mat, cfg.ssm_chunk)
+        y = y + p["d_skip"].astype(jnp.float32)[:, None] * x_in.astype(jnp.float32)
+        y = y.reshape(*xbc.shape[:2], di)
+        new_state = (xbc_raw[:, -(cfg.ssm_conv - 1):].astype(STATE_DTYPE), final)
+    else:
+        conv_state, ssm_state = state  # (b, conv-1, conv_dim), (b, nh, hd, ds)
+        seq = jnp.concatenate([conv_state.astype(xbc_raw.dtype), xbc_raw], axis=1)
+        conv_out = causal_conv1d(seq, p["conv_w"], p["conv_b"])[:, -1:]
+        xbc1 = jax.nn.silu(conv_out.astype(jnp.float32)).astype(h.dtype)[:, 0]
+        x_in = xbc1[..., :di].reshape(-1, nh, cfg.ssm_headdim)
+        b_vec = xbc1[..., di : di + g * ds].reshape(-1, g, ds)
+        c_vec = xbc1[..., di + g * ds :].reshape(-1, g, ds)
+        ssm_state, y = ssd_decode_step(ssm_state, x_in, dt[:, 0], p["a_log"], b_vec, c_vec)
+        y = y + p["d_skip"].astype(jnp.float32)[:, None] * x_in.astype(jnp.float32)
+        y = y.reshape(h.shape[0], 1, di)
+        new_state = (seq[:, 1:].astype(conv_state.dtype), ssm_state)
+
+    # gated RMSNorm then out-projection
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    y = L.rms_norm(y.astype(h.dtype), p["norm_g"], cfg.norm_eps)
+    return jnp.einsum("bsd,dp->bsp", y, p["w_out"].astype(h.dtype)), new_state
+
+
 class Mamba2LM:
     def __init__(self, cfg: ModelConfig, remat_policy: str = "full"):
         self.cfg = cfg
         self.remat_policy = remat_policy
 
     # ------------------------------------------------------------------
-    @property
-    def _dims(self):
-        c = self.cfg
-        di = c.d_inner
-        nh = c.ssm_nheads
-        g, ds = c.ssm_ngroups, c.ssm_state
-        conv_dim = di + 2 * g * ds
-        return di, nh, g, ds, conv_dim
-
-    def _layer_specs(self) -> Dict[str, mod.ParamSpec]:
-        c = self.cfg
-        nl, d = c.n_layers, c.d_model
-        di, nh, g, ds, conv_dim = self._dims
-        proj_out = 2 * di + 2 * g * ds + nh
-        return {
-            "norm": mod.spec((nl, d), ("layers", "embed"), init="ones"),
-            "w_in": mod.spec((nl, d, proj_out), ("layers", "embed", "ssm_inner"), init="scaled"),
-            "conv_w": mod.spec((nl, conv_dim, c.ssm_conv), ("layers", "ssm_inner", "conv"), init="scaled"),
-            "conv_b": mod.spec((nl, conv_dim), ("layers", "ssm_inner"), init="zeros"),
-            "dt_bias": mod.spec((nl, nh), ("layers", "ssm_heads"), init="zeros"),
-            "a_log": mod.spec((nl, nh), ("layers", "ssm_heads"), init="zeros"),
-            "d_skip": mod.spec((nl, nh), ("layers", "ssm_heads"), init="ones"),
-            "norm_g": mod.spec((nl, di), ("layers", "ssm_inner"), init="ones"),
-            "w_out": mod.spec((nl, di, d), ("layers", "ssm_inner", "embed"), init="scaled"),
-        }
-
     def param_specs(self):
         c = self.cfg
         p: Dict[str, Any] = {
             "embed": mod.spec((c.padded_vocab, c.d_model), ("vocab", "embed")),
-            "layers": self._layer_specs(),
+            "layers": mixer_specs(c, c.n_layers),
             "final_norm": mod.spec((c.d_model,), ("embed",), init="ones"),
         }
         if not c.tie_embeddings:
@@ -180,126 +221,60 @@ class Mamba2LM:
         return mod.init_tree(self.param_specs(), key)
 
     # ------------------------------------------------------------------
-    def _split_proj(self, zxbcdt):
-        di, nh, g, ds, conv_dim = self._dims
-        z = zxbcdt[..., :di]
-        xbc = zxbcdt[..., di : di + conv_dim]
-        dt = zxbcdt[..., di + conv_dim :]
-        return z, xbc, dt
+    def _block(self, p, x, state=None):
+        """Pre-norm residual block: ``(x + mixer(rmsnorm(x)), states)``."""
+        h = L.rms_norm(x, p["norm"], self.cfg.norm_eps)
+        out, new_state = mixer(self.cfg, p, h, state)
+        return logical_constraint(x + out, ("batch", "seq", "embed")), new_state
 
-    def _block(self, p, x, mode: str, state=None):
-        """mode: 'train' (full seq) or 'decode' (state = (conv_state, ssm_state))."""
-        c = self.cfg
-        di, nh, g, ds, conv_dim = self._dims
-        h = L.rms_norm(x, p["norm"], c.norm_eps)
-        zxbcdt = jnp.einsum("bsd,dp->bsp", h, p["w_in"].astype(h.dtype))
-        z, xbc, dt = self._split_proj(zxbcdt)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-
-        if mode == "train":
-            xbc = causal_conv1d(xbc, p["conv_w"], p["conv_b"])
-            xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(x.dtype)
-            x_in = xbc[..., :di].reshape(*xbc.shape[:2], nh, c.ssm_headdim)
-            b_mat = xbc[..., di : di + g * ds].reshape(*xbc.shape[:2], g, ds)
-            c_mat = xbc[..., di + g * ds :].reshape(*xbc.shape[:2], g, ds)
-            y, _ = ssd_chunked(x_in, dt, p["a_log"], b_mat, c_mat, c.ssm_chunk)
-            y = y + p["d_skip"].astype(jnp.float32)[:, None] * x_in.astype(jnp.float32)
-            y = y.reshape(*xbc.shape[:2], di)
-            new_state = None
-        else:
-            conv_state, ssm_state = state  # (b, conv-1, conv_dim), (b, nh, hd, ds)
-            seq = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
-            conv_out = causal_conv1d(seq, p["conv_w"], p["conv_b"])[:, -1:]
-            new_conv = seq[:, 1:]
-            xbc1 = jax.nn.silu(conv_out.astype(jnp.float32)).astype(x.dtype)[:, 0]
-            x_in = xbc1[..., :di].reshape(-1, nh, c.ssm_headdim)
-            b_vec = xbc1[..., di : di + g * ds].reshape(-1, g, ds)
-            c_vec = xbc1[..., di + g * ds :].reshape(-1, g, ds)
-            ssm_state, y = ssd_decode_step(
-                ssm_state, x_in, dt[:, 0], p["a_log"], b_vec, c_vec
-            )
-            y = y + p["d_skip"].astype(jnp.float32) [:, None] * x_in.astype(jnp.float32)
-            y = y.reshape(x.shape[0], 1, di)
-            new_state = (new_conv.astype(conv_state.dtype), ssm_state)
-
-        # gated RMSNorm then out-projection
-        y = y * jax.nn.silu(z.astype(jnp.float32))
-        y = L.rms_norm(y.astype(x.dtype), p["norm_g"], c.norm_eps)
-        out = jnp.einsum("bsd,dp->bsp", y, p["w_out"].astype(x.dtype))
-        x = x + out
-        return logical_constraint(x, ("batch", "seq", "embed")), new_state
-
-    # ------------------------------------------------------------------
-    def loss_fn(self, params, batch):
-        c = self.cfg
-        x = L.embed(batch["tokens"], params["embed"])
-        x = logical_constraint(x, ("batch", "seq", "embed"))
-        block = remat_wrap(lambda xx, pp: self._block(pp, xx, "train")[0], self.remat_policy)
-        x, _ = jax.lax.scan(lambda xx, pp: (block(xx, pp), None), x, params["layers"])
-        x = L.rms_norm(x, params["final_norm"], c.norm_eps)
+    def _head(self, params, x):
+        x = L.rms_norm(x, params["final_norm"], self.cfg.norm_eps)
         head = params.get("head")
         if head is None:
             head = params["embed"].T
-        logits = L.lm_logits(x, head)
-        logits = logical_constraint(logits, ("batch", "seq", "vocab"))
-        loss = L.softmax_xent(logits, batch["labels"], batch.get("loss_mask"), valid_vocab=c.vocab_size)
+        return L.lm_logits(x, head)
+
+    def logits(self, params, tokens):
+        """Full-sequence logits (b, s, padded vocab)."""
+        x = L.embed(tokens, params["embed"])
+        x = logical_constraint(x, ("batch", "seq", "embed"))
+        block = remat_wrap(lambda xx, pp: self._block(pp, xx)[0], self.remat_policy)
+        x, _ = jax.lax.scan(lambda xx, pp: (block(xx, pp), None), x, params["layers"])
+        return logical_constraint(self._head(params, x), ("batch", "seq", "vocab"))
+
+    def loss_fn(self, params, batch):
+        logits = self.logits(params, batch["tokens"])
+        loss = L.softmax_xent(logits, batch["labels"], batch.get("loss_mask"),
+                              valid_vocab=self.cfg.vocab_size)
         return loss, {"xent": loss}
 
-    # ------------------------------------------------------------------
-    def _block_prefill(self, p, x):
-        """Full-sequence pass that also returns the final recurrent state."""
-        c = self.cfg
-        di, nh, g, ds, conv_dim = self._dims
-        h = L.rms_norm(x, p["norm"], c.norm_eps)
-        zxbcdt = jnp.einsum("bsd,dp->bsp", h, p["w_in"].astype(h.dtype))
-        z, xbc_raw, dt = self._split_proj(zxbcdt)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-        xbc = causal_conv1d(xbc_raw, p["conv_w"], p["conv_b"])
-        xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(x.dtype)
-        x_in = xbc[..., :di].reshape(*xbc.shape[:2], nh, c.ssm_headdim)
-        b_mat = xbc[..., di : di + g * ds].reshape(*xbc.shape[:2], g, ds)
-        c_mat = xbc[..., di + g * ds :].reshape(*xbc.shape[:2], g, ds)
-        y, final = ssd_chunked(x_in, dt, p["a_log"], b_mat, c_mat, c.ssm_chunk)
-        y = y + p["d_skip"].astype(jnp.float32)[:, None] * x_in.astype(jnp.float32)
-        y = y.reshape(*xbc.shape[:2], di)
-        y = y * jax.nn.silu(z.astype(jnp.float32))
-        y = L.rms_norm(y.astype(x.dtype), p["norm_g"], c.norm_eps)
-        out = jnp.einsum("bsd,dp->bsp", y, p["w_out"].astype(x.dtype))
-        x = x + out
-        conv_state = xbc_raw[:, -(c.ssm_conv - 1):].astype(STATE_DTYPE)
-        return x, (conv_state, final)
+    def row_losses(self, params, batch):
+        """``((B,) mean loss of each row over its masked positions, {})``."""
+        logits = self.logits(params, batch["tokens"])
+        return L.row_xent(logits, batch["labels"], batch["loss_mask"],
+                          valid_vocab=self.cfg.vocab_size), {}
 
+    # ------------------------------------------------------------------
     def prefill(self, params, batch, cache_budget: int = 0):
         # recurrent state is O(1): no budget needed
-        c = self.cfg
         x = L.embed(batch["tokens"], params["embed"])
-        block = remat_wrap(lambda xx, pp: self._block_prefill(pp, xx), self.remat_policy)
+        block = remat_wrap(lambda xx, pp: self._block(pp, xx), self.remat_policy)
         x, states = jax.lax.scan(lambda xx, pp: block(xx, pp), x, params["layers"])
-        x = L.rms_norm(x, params["final_norm"], c.norm_eps)
-        head = params.get("head")
-        if head is None:
-            head = params["embed"].T
-        logits = L.lm_logits(x[:, -1:], head)[..., : c.vocab_size]
+        logits = self._head(params, x[:, -1:])[..., : self.cfg.vocab_size]
         conv_states, ssm_states = states
         return {"conv": conv_states, "ssm": ssm_states}, logits
 
     def decode_step(self, params, cache, batch):
-        c = self.cfg
         x = L.embed(batch["token"], params["embed"])
 
         def scan_body(xx, per_layer):
             pp, conv_s, ssm_s = per_layer
-            xx, (conv_s, ssm_s) = self._block(pp, xx, "decode", (conv_s, ssm_s))
-            return xx, (conv_s, ssm_s)
+            return self._block(pp, xx, (conv_s, ssm_s))
 
         x, (conv_n, ssm_n) = jax.lax.scan(
             scan_body, x, (params["layers"], cache["conv"], cache["ssm"])
         )
-        x = L.rms_norm(x, params["final_norm"], c.norm_eps)
-        head = params.get("head")
-        if head is None:
-            head = params["embed"].T
-        logits = L.lm_logits(x, head)[..., : c.vocab_size]
+        logits = self._head(params, x)[..., : self.cfg.vocab_size]
         return {"conv": conv_n, "ssm": ssm_n}, logits
 
     # ------------------------------------------------------------------
@@ -322,7 +297,7 @@ class Mamba2LM:
     def cache_specs(self, shape: ShapeConfig) -> Dict[str, Any]:
         c = self.cfg
         b = shape.global_batch
-        di, nh, g, ds, conv_dim = self._dims
+        di, nh, g, ds, conv_dim = mixer_dims(c)
         return {
             "conv": mod.spec(
                 (c.n_layers, b, c.ssm_conv - 1, conv_dim),

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 from repro.configs.base import ModelConfig
 from repro.models.encdec import EncDecLM
+from repro.models.granite_hybrid import GraniteHybridLM
 from repro.models.mamba2 import Mamba2LM
 from repro.models.rglru import GriffinLM
 from repro.models.transformer import TransformerLM
@@ -20,6 +21,8 @@ def build_model(cfg: ModelConfig, remat_policy: str = "full"):
         return Mamba2LM(cfg, remat_policy)
     if cfg.family == "hybrid":
         return GriffinLM(cfg, remat_policy)
+    if cfg.family == "hybrid_moe":
+        return GraniteHybridLM(cfg, remat_policy)
     if cfg.family == "encdec":
         return EncDecLM(cfg, remat_policy)
     raise ValueError(f"unknown family {cfg.family!r}")

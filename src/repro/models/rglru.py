@@ -259,17 +259,28 @@ class GriffinLM:
         return x, {"super": new_super, "tail": new_tail}
 
     # ------------------------------------------------------------------
-    def loss_fn(self, params, batch):
+    def logits(self, params, tokens):
+        """Full-sequence logits (b, s, padded vocab)."""
         c = self.cfg
-        x = L.embed(batch["tokens"], params["embed"])
+        x = L.embed(tokens, params["embed"])
         x = logical_constraint(x, ("batch", "seq", "embed"))
         positions = jnp.arange(x.shape[1])
         x, _ = self._forward(params, x, positions, "train")
         x = L.rms_norm(x, params["final_norm"], c.norm_eps)
         logits = L.lm_logits(x, params["head"])
-        logits = logical_constraint(logits, ("batch", "seq", "vocab"))
-        loss = L.softmax_xent(logits, batch["labels"], batch.get("loss_mask"), valid_vocab=c.vocab_size)
+        return logical_constraint(logits, ("batch", "seq", "vocab"))
+
+    def loss_fn(self, params, batch):
+        logits = self.logits(params, batch["tokens"])
+        loss = L.softmax_xent(logits, batch["labels"], batch.get("loss_mask"),
+                              valid_vocab=self.cfg.vocab_size)
         return loss, {"xent": loss}
+
+    def row_losses(self, params, batch):
+        """``((B,) mean loss of each row over its masked positions, {})``."""
+        logits = self.logits(params, batch["tokens"])
+        return L.row_xent(logits, batch["labels"], batch["loss_mask"],
+                          valid_vocab=self.cfg.vocab_size), {}
 
     def prefill(self, params, batch, cache_budget: int = 0):
         # local-attention caches are windowed rings: no budget needed

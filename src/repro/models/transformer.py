@@ -165,7 +165,9 @@ class TransformerLM:
             x = jnp.concatenate([pe, x], axis=1)
         return logical_constraint(x, ("batch", "seq", "embed"))
 
-    def loss_fn(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    def logits(self, params, batch) -> Tuple[jax.Array, jax.Array]:
+        """Full-sequence logits (b, s, padded vocab) and the layers' MoE
+        auxiliary losses."""
         c = self.cfg
         x = self._backbone_inputs(params, batch)
         positions = jnp.arange(x.shape[1])
@@ -185,11 +187,21 @@ class TransformerLM:
         if c.family == "vlm":  # drop patch positions before the LM head
             x = x[:, c.n_patches :]
         logits = L.lm_logits(x, head)
-        logits = logical_constraint(logits, ("batch", "seq", "vocab"))
+        return logical_constraint(logits, ("batch", "seq", "vocab")), auxs
+
+    def loss_fn(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        c = self.cfg
+        logits, auxs = self.logits(params, batch)
         loss = L.softmax_xent(logits, batch["labels"], batch.get("loss_mask"), valid_vocab=c.vocab_size)
         aux = jnp.sum(auxs) if c.family == "moe" else 0.0
         total = loss + MOE_AUX_COEF * aux
         return total, {"xent": loss, "moe_aux": jnp.asarray(aux, jnp.float32)}
+
+    def row_losses(self, params, batch):
+        """``((B,) mean loss of each row over its masked positions, {})``."""
+        logits, _ = self.logits(params, batch)
+        return L.row_xent(logits, batch["labels"], batch["loss_mask"],
+                          valid_vocab=self.cfg.vocab_size), {}
 
     # ------------------------------------------------------------------
     # Serve: prefill + decode

@@ -81,22 +81,22 @@ class NgramPerplexityFilter(_RangeFilter):
         return float(math.exp(-logp / (len(ids) - 1)))
 
 
-def batch_scorer(model):
-    """Jitted ``(params, tokens, labels, mask) -> (B,)`` per-row mean losses:
-    ONE call scores the whole batch (vmap of the model's batch-of-one
-    ``loss_fn``). Built from the model alone, so the step can be lowered and
-    compiled from abstract params (``jax.eval_shape``) without allocating
-    them."""
+def batch_scorer(model, with_stats: bool = False):
+    """Jitted ``(params, tokens, labels, mask) -> (B,)``: each row's mean
+    loss over its masked positions from ONE call of the model's
+    ``row_losses`` over the whole batch, so rows that share a layer (an
+    expert layer's routing) share one pass. ``with_stats``: returns
+    ``(losses, metrics)``, the model's per-step counts beside the losses.
+    Built from the model alone, so the step can be lowered and compiled from
+    abstract params (``jax.eval_shape``) without allocating them."""
     import jax
 
     def score_one(params, tokens, labels, mask):
-        loss, _ = model.loss_fn(
-            params,
-            {"tokens": tokens[None], "labels": labels[None], "loss_mask": mask[None]},
-        )
-        return loss
+        losses, stats = model.row_losses(
+            params, {"tokens": tokens, "labels": labels, "loss_mask": mask})
+        return (losses, stats) if with_stats else losses
 
-    return jax.jit(jax.vmap(score_one, in_axes=(None, 0, 0, 0)))
+    return jax.jit(score_one)
 
 
 def encode_rows(tok, texts: List[str], seq: int):
@@ -123,8 +123,11 @@ class LMPerplexityFilter(_RangeFilter):
     ``arch`` picks any assigned architecture (reduced config by default so
     the OP runs on CPU); ``params_path`` can point at a trained checkpoint
     (e.g. produced by examples/train_e2e.py — data-model co-development).
-    A chunked SSM scores ``seq_len`` rounded up to a multiple of its chunk,
-    the only lengths its chunked scan accepts.
+    A model with chunked SSM layers scores ``seq_len`` rounded up to a
+    multiple of its chunk, the only lengths its chunked scan accepts. With
+    an expert layer, each ``scorer.step`` also counts the assignments its
+    held experts computed at scored positions: ``scorer.moe_rows`` (all of
+    them) and ``scorer.moe_rows_max`` (the busiest expert of any layer).
     """
 
     stat_key = "lm_ppl"
@@ -160,12 +163,13 @@ class LMPerplexityFilter(_RangeFilter):
         else:
             self._params = self._model.init_params(jax.random.PRNGKey(0))
         self._tok = HashWordTokenizer(cfg.vocab_size)
-        chunk = cfg.ssm_chunk if cfg.family == "ssm" else 1
+        chunk = cfg.ssm_chunk if cfg.has_ssm_layers else 1
         self._seq = -(-self.params["seq_len"] // chunk) * chunk
-        self._score_batch = batch_scorer(self._model)
+        self._score_batch = batch_scorer(self._model, with_stats=True)
 
     def _ppl_batch(self, texts: List[str]) -> np.ndarray:
         self.setup()
+        import jax
         import jax.numpy as jnp
 
         with obs.timed("scorer.encode"):
@@ -180,13 +184,17 @@ class LMPerplexityFilter(_RangeFilter):
                 toks = np.pad(toks, ((0, nb - n), (0, 0)))
                 labels = np.pad(labels, ((0, nb - n), (0, 0)))
                 mask = np.pad(mask, ((0, nb - n), (0, 0)))
-                mask[n:, 0] = 1.0  # avoid 0/0 in padded rows
         with obs.timed("scorer.step", {"scorer.tokens_scored": scored,
-                                       "scorer.tokens_padded": toks.size - scored}):
-            losses = self._score_batch(
+                                       "scorer.tokens_padded": toks.size - scored}) as t:
+            # the counts come back in the losses' one read
+            losses, stats = jax.device_get(self._score_batch(
                 self._params, jnp.asarray(toks), jnp.asarray(labels), jnp.asarray(mask)
-            )
+            ))
             losses = np.asarray(losses, np.float64)[:n]
+            if "moe_rows" in stats:
+                rows = np.asarray(stats["moe_rows"], np.float64)
+                t.add("scorer.moe_rows", rows.sum())
+                t.add("scorer.moe_rows_max", rows.max())
         return np.exp(np.minimum(losses, 30.0))
 
     def process_batch(self, batch):

@@ -81,6 +81,27 @@ def test_scorer_step_fits_one_v5e(one_chip, no_persistent_cache):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_LIMIT
 
 
+def test_granite_share_step_fits_one_v5e(one_chip, no_persistent_cache):
+    """The step of granite-4.0-h-small's one-chip share (10 layers, 9 of 72
+    experts, bf16 parameters) at B=64 rows of T=256 tokens: the grouped
+    matmul compiles to the chip's kernel, and parameters and working set fit."""
+    from repro.configs import get_config
+    from repro.models.model_zoo import build_model
+    from repro.ops.model_ops import batch_scorer
+
+    model = build_model(get_config("granite-4.0-h-small-ep8"), remat_policy="none")
+    params = jax.tree.map(
+        lambda x: _abstract(x.shape, x.dtype, one_chip),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    tokens = _abstract((64, 256), jnp.int32, one_chip)
+    mask = _abstract((64, 256), jnp.float32, one_chip)
+    compiled = batch_scorer(model, with_stats=True).lower(params, tokens, tokens, mask).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the grouped-matmul kernel
+    mem = compiled.memory_analysis()
+    assert 4.0e9 < mem.argument_size_in_bytes < 4.3e9  # about 2.06 B bf16 parameters
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_LIMIT
+
+
 def test_blake2b64_tile_compiles_for_v5e(one_chip, no_persistent_cache):
     """The shingle hash at the one tile shape the block shingler dispatches."""
     from repro.kernels.blake2b.blake2b import blake2b64_tile

@@ -33,8 +33,17 @@ def _row_losses(op, texts):
     ("mamba2-1.3b", 12, 16),  # reduced chunk 8 does not divide 12: rounds up
     ("mamba2-1.3b", 16, 16),  # chunk divides: unchanged
     ("phi3-medium-14b", 12, 12),  # no chunked scan: unchanged
+    ("granite-4.0-h-small-ep8", 12, 16),  # Mamba-2 layers among attention: rounds up
 ])
-def test_lm_perplexity_scored_length(arch, seq_len, scored_len):
+def test_lm_perplexity_scored_length(arch, seq_len, scored_len, monkeypatch):
+    if arch.startswith("granite"):
+        # its residual stream is float32 and its mixers' inputs bfloat16
+        # casts of it: the CPU's matmul kernels, picked by the batch's
+        # shape, round the last bit differently for 10 rows and for 1, and a
+        # cast can turn that into an ulp. In float32 the rows agree.
+        import repro.models.layers as L
+
+        monkeypatch.setattr(L, "COMPUTE_DTYPE", np.float32)
     samples = make_corpus(10, seed=3)
     op = create_op({"name": "lm_perplexity_filter", "arch": arch,
                     "reduced": True, "seq_len": seq_len})
